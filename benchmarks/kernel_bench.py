@@ -1,7 +1,7 @@
 """Pallas kernel microbench: interpret-mode on CPU validates + times the
-reference XLA path (us/call).  Real-TPU timings come from the same wrappers
-with use_pallas('tpu'); derived column reports the modelled VMEM-resident
-HBM-traffic advantage vs the unfused jnp path."""
+reference XLA path (us/call).  On a TPU the same kernels compile with
+Mosaic (``kernels.lanes.interpret_mode``); derived column reports the
+modelled VMEM-resident HBM-traffic advantage vs the unfused jnp path."""
 import time
 
 import jax

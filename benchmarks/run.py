@@ -27,6 +27,7 @@ import sys
 import traceback
 
 from benchmarks.common import ART
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run_figures(force: bool, mini: bool) -> None:
@@ -60,6 +61,7 @@ def main() -> None:
                          "2 benchmarks with small ROUNDS; the fabric suite "
                          "shrinks its op counts")
     args = ap.parse_args()
+    enable_compile_cache()
 
     print("name,us_per_call,derived")
     if args.suite == "figures":

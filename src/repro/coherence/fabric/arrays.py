@@ -51,7 +51,7 @@ from repro.core import protocol
 from repro.core import state as S
 from repro.core.state import TSUState, TierState
 from repro.obs import trace as obs
-from repro.sharding import named_sharding, shard_map
+from repro.sharding import named_sharding
 
 _NOP, _READ, _WRITE, _FENCE, _MM_WRITE, _PUBLISH, _MM_READ = range(7)
 _PRUNE_EVERY = 4096          # payload-map GC cadence, in completed writes
@@ -119,7 +119,7 @@ def _build_run(S1s, W1, S2s, W2, KS, CAP, NN, NR, Q, MAXIF, LD, MESH=None,
     ArrayFabric instance with the same shape shares one compilation.
 
     With ``MESH`` (a 1-axis ``fabric`` mesh) the scan becomes a
-    ``repro.sharding.shard_map`` body: the TSU table and its per-shard
+    ``jax.shard_map`` body: the TSU table and its per-shard
     sequencers are laid out along the mesh axis (each device owns
     ``KS / D`` contiguous shards — the paper's one-TSU-per-HBM-stack
     placement).  Client tiers, write-queue rings and counters stay
@@ -603,9 +603,9 @@ def _build_run(S1s, W1, S2s, W2, KS, CAP, NN, NR, Q, MAXIF, LD, MESH=None,
     # partitioned along the fabric axis, everything else replicated;
     # the per-op results come back replicated (identical on every
     # device by construction)
-    return jax.jit(shard_map(run, MESH,
-                             in_specs=(af_spec, P(), P(), P()),
-                             out_specs=(af_spec, P()), check_vma=False),
+    return jax.jit(jax.shard_map(run, mesh=MESH,
+                                 in_specs=(af_spec, P(), P(), P()),
+                                 out_specs=(af_spec, P()), check_vma=False),
                    donate_argnums=0)
 
 
@@ -654,8 +654,8 @@ def _build_fast_read(mesh=None):
 
     if mesh is None:
         return jax.jit(fast)
-    return jax.jit(shard_map(fast, mesh, in_specs=(P(),) * 8,
-                             out_specs=(P(),) * 5, check_vma=False))
+    return jax.jit(jax.shard_map(fast, mesh=mesh, in_specs=(P(),) * 8,
+                                 out_specs=(P(),) * 5, check_vma=False))
 
 
 @functools.lru_cache(maxsize=32)
@@ -715,8 +715,8 @@ def _build_tsu_gather(MESH):
         return S.unpack_tsu(S.owner_gather(
             S.pack_tsu(tsu, ver, gseq, seq, nseq), "fabric"))
 
-    return jax.jit(shard_map(body, MESH, in_specs=(F,) * 5,
-                             out_specs=(P(),) * 5, check_vma=False))
+    return jax.jit(jax.shard_map(body, mesh=MESH, in_specs=(F,) * 5,
+                                 out_specs=(P(),) * 5, check_vma=False))
 
 
 class ArrayFabric(FabricBackend):
@@ -782,7 +782,9 @@ class ArrayFabric(FabricBackend):
         # (ISSUE 8 tentpole, DESIGN.md §12a)
         if mesh is not None and pipeline == "batched":
             self._gather_run = _build_tsu_gather(mesh)
-            self._dev0 = jax.devices()[0]
+            # the mesh's own lead device: a mesh built from other devices
+            # must not run its passes off-mesh
+            self._dev0 = mesh.devices.flat[0]
             f3 = named_sharding(mesh, (self._KS, 1, self._CAP + 1),
                                 ("fabric_shard", None, None))
             f1 = named_sharding(mesh, (self._KS,), ("fabric_shard",))
@@ -836,7 +838,7 @@ class ArrayFabric(FabricBackend):
                 # dev0 pass engine: only the TSU — the state of record the
                 # per-batch gather assembles — lives on the mesh; every
                 # other leaf stays on the lead device where the passes run
-                af = af._replace(
+                af = jax.device_put(af, self._dev0)._replace(
                     tsu=jax.device_put(af.tsu, f3),
                     tsu_ver=jax.device_put(af.tsu_ver, f3),
                     tsu_gseq=jax.device_put(af.tsu_gseq, f3),
@@ -878,10 +880,10 @@ class ArrayFabric(FabricBackend):
         dev0 = self._dev0
 
         def local(x):
-            for s in x.addressable_shards:
-                if s.device == dev0:
-                    return s.data
-            return jax.device_put(x, dev0)
+            # replicated over the mesh, so the lead device holds a copy
+            (data,) = [s.data for s in x.addressable_shards
+                       if s.device == dev0]
+            return data
 
         tsu, ver, gseq, seq, nseq = jax.tree_util.tree_map(local, full)
         return self._af._replace(tsu=tsu, tsu_ver=ver, tsu_gseq=gseq,
@@ -1412,8 +1414,8 @@ class ShardedArrayFabric(ArrayFabric):
     guard.  This backend realizes that placement: the ``[n_shards,
     capacity]`` TSU table (plus the per-shard grant sequencers and
     version/gseq side arrays) is partitioned over the ``fabric`` mesh axis
-    with ``NamedSharding`` and the op-scan runs as a ``repro.sharding.
-    shard_map`` body.  Under the default batched grant pipeline the owned
+    with ``NamedSharding`` and the op-scan runs as a ``jax.shard_map``
+    body.  Under the default batched grant pipeline the owned
     TSU rows are exchanged as ONE packed collective per batch (DESIGN.md
     §9); under ``pipeline="scan"`` each op's TSU transition executes only
     on its key's owning device and the grant hops back per scan step (the

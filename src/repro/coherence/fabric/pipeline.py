@@ -69,7 +69,7 @@ import numpy as np
 
 from repro.coherence.fabric.stats import GI, G_KEYS, RI, R_KEYS
 from repro.core import state as S
-from repro.kernels import ops as K
+from repro.kernels.tier_pass import miss_round
 # the packed per-op result block ([7, M] int32) — the layout contract now
 # lives in core.state so the simulator's round step emits the same record
 # (re-exported here for existing consumers)
@@ -225,7 +225,7 @@ def make_miss_pass(W1: int, W2: int, KS: int):
         # per DESIGN.md §12c.  Only the cross-lane state scatters
         # (self-invalidation, LRU touch/fill, TSU commit) stay outside.
         (th1, h1, way1, th2, h2, way2, fndF, tway, mwts, mrts, nmem, ovf,
-         nwA, nrA, nw1, nr1) = K.miss_round(
+         nwA, nrA, nw1, nr1) = miss_round(
             af.rp.tag[reps, s1][..., :-1], af.rp.rts[reps, s1][..., :-1],
             af.sh.tag[nodes, s2][..., :-1], af.sh.rts[nodes, s2][..., :-1],
             af.sh.wts[nodes, s2][..., :-1],

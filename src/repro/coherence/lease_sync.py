@@ -110,12 +110,11 @@ def make_lease_window_step(cfg, mesh, opt: adamw.AdamWConfig,
     def window_step(state, batches):
         bspec = jax.tree.map(lambda _: P(None, "pod"), batches)
         sspec = jax.tree.map(lambda _: P(), state)
-        import repro.sharding as sharding
-        return sharding.shard_map(local_window, mesh=mesh,
-                                  in_specs=(sspec, bspec),
-                                  out_specs=(sspec, P()),
-                                  axis_names={"pod"},
-                                  check_vma=False)(state, batches)
+        return jax.shard_map(local_window, mesh=mesh,
+                             in_specs=(sspec, bspec),
+                             out_specs=(sspec, P()),
+                             axis_names={"pod"},
+                             check_vma=False)(state, batches)
 
     return window_step
 

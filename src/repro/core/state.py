@@ -43,8 +43,10 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import protocol
-from repro.kernels.lease_probe import lease_probe
-from repro.kernels.tier_pass import write_grant
+# module imports (not names): kernels.tier_pass imports core.protocol, so
+# importing it first must not need this module fully initialized
+from repro.kernels import lease_probe as probe_kernel
+from repro.kernels import tier_pass
 
 INVALID = jnp.int32(-1)
 
@@ -252,9 +254,9 @@ def tier_probe(tier: TierState, idx, set_idx, addr, mwts, mrts):
     the probe half may pass zeros for (mwts, mrts) and ignore the install
     outputs; callers that only need the install half ignore the hit outputs.
     """
-    return lease_probe(tier.tag[idx, set_idx][..., :-1],
-                       tier.rts[idx, set_idx][..., :-1],
-                       tier.cts[idx], addr, mwts, mrts)
+    return probe_kernel.lease_probe(tier.tag[idx, set_idx][..., :-1],
+                                    tier.rts[idx, set_idx][..., :-1],
+                                    tier.cts[idx], addr, mwts, mrts)
 
 
 # ------------------------------------------------- packed contiguous buffers
@@ -365,7 +367,7 @@ def tsu_commit_write_batch(tsu: TSUState, ver_arr, gseq_arr, seq_arr, nseq,
     # fused probe + lex victim + mm_write grant (ONE Pallas grid pass —
     # kernels.tier_pass.write_grant, the write-side twin of the miss
     # round's fused kernel; same victim_lex/tsu_lease math, bit-exact)
-    th, w0, full, g_wts, g_rts, g_memts, g_ovf = write_grant(
+    th, w0, full, g_wts, g_rts, g_memts, g_ovf = tier_pass.write_grant(
         tsu.tag[shard, zset][..., :-1], tsu.memts[shard, zset][..., :-1],
         seq_arr[shard, zset][..., :-1], key,
         jnp.broadcast_to(jnp.asarray(wr_eff, i32), key.shape))

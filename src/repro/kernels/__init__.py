@@ -1,3 +1,3 @@
-from repro.kernels.ops import (decode_attention, flash_attention,  # noqa: F401
-                               lease_probe, miss_round, rmsnorm, ssd_chunk,
-                               use_pallas, write_grant)
+"""Pallas kernels.  Each kernel module is imported directly; the backend
+rule (compiled on an accelerator, interpret mode on the CPU) is
+``kernels.lanes.interpret_mode``."""

@@ -11,6 +11,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.lanes import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -46,7 +48,7 @@ def _decode_kernel(kvlen_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("bk", "interpret"))
-def decode_attention(q, k, v, kv_len, *, bk=512, interpret=True):
+def decode_attention(q, k, v, kv_len, *, bk=512, interpret=None):
     """q: [B,1,Hq,D]; k,v: [B,Sk,Hkv,D]; kv_len: scalar int32."""
     B, Sq, Hq, D = q.shape
     assert Sq == 1
@@ -80,6 +82,6 @@ def decode_attention(q, k, v, kv_len, *, bk=512, interpret=True):
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, Hq, 1, D), q.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(jnp.asarray(kv_len, jnp.int32).reshape(1), qt, kt, vt)
     return out.transpose(0, 2, 1, 3)

@@ -15,6 +15,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.lanes import interpret_mode
+
 NEG_INF = -1e30
 
 
@@ -60,7 +62,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 @functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
                                              "interpret"))
 def flash_attention(q, k, v, *, causal=True, window=0, bq=128, bk=128,
-                    interpret=True):
+                    interpret=None):
     """q: [B, Sq, Hq, D]; k, v: [B, Sk, Hkv, D] -> [B, Sq, Hq, D]."""
     B, Sq, Hq, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
@@ -93,7 +95,7 @@ def flash_attention(q, k, v, *, causal=True, window=0, bq=128, bk=128,
             _vmem((bq,), jnp.float32),
             _vmem((bq,), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3)
 

@@ -5,11 +5,9 @@ single fused VMEM pass — the Pallas face of repro.core.protocol, and since
 the batched sweep engine (DESIGN.md §5) the op that serves every L1 and L2
 probe+install inside ``core.engine``'s round step.
 
-Backend selection is a runtime decision: with ``interpret=None`` (the
-default, used by the engine) the kernel compiles natively on TPU/GPU and
-falls back to interpret mode on CPU, where Pallas has no native lowering.
-Interpret mode traces the identical kernel body into plain XLA ops, so the
-engine's math is bit-identical across backends.
+Layout and backend follow ``kernels.lanes``: requests on the lane axis,
+ways down the sublanes; compiled with Mosaic on the TPU, interpret mode on
+the CPU — bit-identical int32 math either way.
 """
 from __future__ import annotations
 
@@ -17,39 +15,32 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
+
+from repro.kernels.lanes import at_first, first_index, lane_call
 
 
-def _probe_kernel(tag_ref, rts_ref, cts_ref, addr_ref, mwts_ref, mrts_ref,
-                  taghit_ref, hit_ref, way_ref, rowrts_ref, nwts_ref,
-                  nrts_ref, ncts_ref):
-    tags = tag_ref[...]                                 # [bn, W]
-    rts = rts_ref[...]
-    cts = cts_ref[...]
-    addr = addr_ref[...]
-    eq = tags == addr[:, None]
-    tag_hit = eq.any(axis=-1)
-    way = jnp.argmax(eq, axis=-1).astype(jnp.int32)
+def _probe_kernel(tag_ref, rts_ref, vec_ref, out_ref):
+    tags = tag_ref[...]                                 # [W, bn]
+    cts, addr, mwts, mrts = (vec_ref[j:j + 1, :] for j in range(4))
+    eq = tags == addr
+    idx, _ = first_index(eq)
+    tag_hit = idx < tags.shape[0]
     # first-match way only: the engine can hold a stale duplicate of a tag
     # (coherence-miss installs go to a victim way while the expired copy
     # stays live), and the probe must read the same way argmax selects
-    first = eq & (jnp.cumsum(eq.astype(jnp.int32), axis=-1) == 1)
-    row_rts = jnp.sum(jnp.where(first, rts, 0), axis=-1)
+    row_rts = at_first(eq, rts_ref[...])
     hit = tag_hit & (cts <= row_rts)                    # protocol.valid
     # protocol.install: Bwts = max(cts, Mwts); Brts = max(Bwts+1, Mrts)
-    bwts = jnp.maximum(cts, mwts_ref[...])
-    brts = jnp.maximum(bwts + 1, mrts_ref[...])
-    taghit_ref[...] = tag_hit.astype(jnp.int32)
-    hit_ref[...] = hit.astype(jnp.int32)
-    way_ref[...] = way
-    rowrts_ref[...] = row_rts
-    nwts_ref[...] = bwts
-    nrts_ref[...] = brts
-    ncts_ref[...] = jnp.maximum(cts, bwts)              # cts_after_write
+    bwts = jnp.maximum(cts, mwts)
+    brts = jnp.maximum(bwts + 1, mrts)
+    for j, v in enumerate((tag_hit.astype(jnp.int32), hit.astype(jnp.int32),
+                           jnp.where(tag_hit, idx, 0), row_rts, bwts, brts,
+                           jnp.maximum(cts, bwts))):    # cts_after_write
+        out_ref[j:j + 1, :] = v
 
 
-@functools.partial(jax.jit, static_argnames=("bn", "interpret"))
-def lease_probe(tag_rows, rts_rows, cts, addr, mwts, mrts, *, bn=256,
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def lease_probe(tag_rows, rts_rows, cts, addr, mwts, mrts, *,
                 interpret=None):
     """Fused probe + install over gathered set rows.
 
@@ -61,33 +52,14 @@ def lease_probe(tag_rows, rts_rows, cts, addr, mwts, mrts, *, bn=256,
     Returns (tag_hit, hit, way, row_rts, new_wts, new_rts, new_cts):
       tag_hit  — tag match on a live way (coherency misses = tag_hit & ~hit)
       hit      — tag match AND lease valid (cts <= rts;  protocol.valid)
-      way      — the matching way (meaningful only under tag_hit)
+      way      — the first matching way (0 when no tag match)
       row_rts  — rts of the matching way (0 when no tag match)
       new_wts/new_rts — protocol.install(cts, mwts, mrts)
       new_cts  — protocol.cts_after_write(cts, new_wts)
 
-    ``interpret=None`` selects the backend at runtime: compiled Pallas on
-    TPU/GPU, interpret fallback on CPU."""
-    if interpret is None:
-        interpret = jax.default_backend() not in ("tpu", "gpu", "cuda",
-                                                  "rocm")
-    N, W = tag_rows.shape
-    bn = min(bn, N)
-    while N % bn:
-        bn -= 1
-    grid = (N // bn,)
-    row = lambda i: (i, 0)
-    vec = lambda i: (i,)
-    outs = pl.pallas_call(
-        _probe_kernel,
-        grid=grid,
-        in_specs=[pl.BlockSpec((bn, W), row), pl.BlockSpec((bn, W), row),
-                  pl.BlockSpec((bn,), vec), pl.BlockSpec((bn,), vec),
-                  pl.BlockSpec((bn,), vec), pl.BlockSpec((bn,), vec)],
-        out_specs=[pl.BlockSpec((bn,), vec)] * 7,
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.int32)] * 7,
-        interpret=interpret,
-    )(tag_rows, rts_rows, cts, addr, mwts, mrts)
-    tag_hit, hit, way, row_rts, nwts, nrts, ncts = outs
+    ``interpret=None`` applies the ``kernels.lanes`` backend rule."""
+    out = lane_call(_probe_kernel, [tag_rows, rts_rows],
+                    [cts, addr, mwts, mrts], 7, interpret)
+    tag_hit, hit, way, row_rts, nwts, nrts, ncts = out
     return (tag_hit.astype(bool), hit.astype(bool), way, row_rts, nwts,
             nrts, ncts)

@@ -8,6 +8,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.lanes import interpret_mode
+
 
 def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps):
     x = x_ref[...].astype(jnp.float32)
@@ -17,7 +19,7 @@ def _rmsnorm_kernel(x_ref, w_ref, o_ref, *, eps):
 
 
 @functools.partial(jax.jit, static_argnames=("eps", "br", "interpret"))
-def rmsnorm(x, w, *, eps=1e-6, br=256, interpret=True):
+def rmsnorm(x, w, *, eps=1e-6, br=256, interpret=None):
     """x: [..., D]; w: [D]."""
     orig = x.shape
     D = orig[-1]
@@ -35,6 +37,6 @@ def rmsnorm(x, w, *, eps=1e-6, br=256, interpret=True):
                   pl.BlockSpec((D,), lambda i: (0,))],
         out_specs=pl.BlockSpec((br, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((R, D), x.dtype),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x2, w)
     return out.reshape(orig)

@@ -12,6 +12,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.lanes import interpret_mode
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, cum_ref):
     x = x_ref[0, 0, :, 0].astype(jnp.float32)          # [Q, P]
@@ -34,7 +36,7 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, st_ref, cum_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def ssd_chunk(x, dt, A, Bc, Cc, *, interpret=True):
+def ssd_chunk(x, dt, A, Bc, Cc, *, interpret=None):
     """x: [B,nc,Q,H,P]; dt: [B,nc,Q,H]; A: [H]; Bc/Cc: [B,nc,Q,H,N].
 
     Returns (y_intra [B,nc,Q,H,P], chunk_state [B,nc,H,N,P], cum [B,nc,Q,H]).
@@ -62,6 +64,6 @@ def ssd_chunk(x, dt, A, Bc, Cc, *, interpret=True):
             jax.ShapeDtypeStruct((Bs, nc, H, N, P), jnp.float32),
             jax.ShapeDtypeStruct((Bs, nc, Q, H), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(x, dt, A, Bc, Cc)
     return y, st, cum

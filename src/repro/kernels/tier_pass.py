@@ -1,11 +1,11 @@
-"""HALCONE fused miss/write-pass round kernels (ISSUE 8 tentpole, lever 3).
+"""HALCONE fused miss/write-pass round kernels.
 
 The batched grant pipeline's round bodies (``coherence.fabric.pipeline``)
-are built from per-lane decision math that previously ran as two separate
-``lease_probe`` launches plus a dozen gather/select XLA ops per round.
-The ``[R, M]`` round masks and the prefix-sum LRU/drain schedules are all
-static-shaped, so the whole per-lane decision surface fuses into ONE
-Pallas grid pass over the request lanes, the way ``kernels.lease_probe``
+are built from per-lane decision math that would otherwise run as two
+separate ``lease_probe`` launches plus a dozen gather/select XLA ops per
+round.  The ``[R, M]`` round masks and the prefix-sum LRU/drain schedules
+are all static-shaped, so the whole per-lane decision surface fuses into
+ONE Pallas grid pass over the request lanes, the way ``kernels.lease_probe``
 fused probe+install for the op-scan:
 
   * ``miss_round`` — the read-side round math: replica probe, shared
@@ -21,19 +21,11 @@ fused probe+install for the op-scan:
 Everything is int32 lattice math — no floats — so fusion is bit-exact by
 construction; the parity suites pin it to ``HostFabric`` end to end.
 
-Backend selection matches ``lease_probe``: with ``interpret=None`` the
-kernels compile natively on TPU/GPU and fall back to interpret mode on
-CPU, where Pallas has no native lowering.  Interpret mode traces the
-identical kernel body into plain XLA ops, so the passes are bit-identical
-across backends.
-
-Layout contract (DESIGN.md §12c): lanes are blocked over a 1-D grid
-``(N // bn,)``; every per-lane vector is ``BlockSpec((bn,), lambda i:
-(i,))`` and every gathered set-row matrix ``[N, W]`` is ``BlockSpec((bn,
-W), lambda i: (i, 0))`` — whole way-rows live in one block, so way
-reductions (first-match, victim argmin) never cross block boundaries.
-``bn`` shrinks to the largest divisor of ``N``; callers pass pow2-padded
-lane counts so ``bn`` stays a pow2 bucket.
+Layout and backend follow ``kernels.lanes`` (DESIGN.md §12c): requests on
+the lane axis, the ways of each gathered set row — up to a whole TSU
+shard — down the sublanes of one block, so way reductions (first match,
+victim) never cross block boundaries; compiled with Mosaic on the TPU,
+interpret mode on the CPU.
 """
 from __future__ import annotations
 
@@ -41,58 +33,46 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.protocol import TS_MAX
+from repro.kernels.lanes import at_first, first_index, lane_call
 
 _INVALID = -1          # core.state.INVALID (empty way); pinned by tests
 _NEG = -2 ** 30
+_MISS_OUTS = 16
+_WRITE_OUTS = 7
 
 
-def _first_match(eq, rows):
-    """Value of ``rows`` at the FIRST matching way (0 when no match)."""
-    first = eq & (jnp.cumsum(eq.astype(jnp.int32), axis=-1) == 1)
-    return jnp.sum(jnp.where(first, rows, 0), axis=-1)
-
-
-def _b(ref):
-    return ref[...] != 0
+def _probe(tags, addr):
+    """(tag hit, first matching way or 0, match mask) of one set row."""
+    eq = tags == addr
+    idx, _ = first_index(eq)
+    hit = idx < tags.shape[0]
+    return hit, jnp.where(hit, idx, 0), eq
 
 
 def _miss_round_kernel(rp_tag_ref, rp_rts_ref, sh_tag_ref, sh_rts_ref,
-                       sh_wts_ref, ts_tag_ref, ts_mem_ref, cts1_ref,
-                       cts2_ref, addr_ref, act_ref, rd_ref,
-                       th1_ref, h1_ref, way1_ref, th2_ref, h2_ref, way2_ref,
-                       fnd_ref, tway_ref, mwts_ref, mrts_ref, nmem_ref,
-                       ovf_ref, nwa_ref, nra_ref, nw1_ref, nr1_ref):
-    i32 = jnp.int32
-    addr = addr_ref[...]
-    act = _b(act_ref)
+                       sh_wts_ref, ts_tag_ref, ts_mem_ref, vec_ref, out_ref):
+    cts1, cts2, addr, act, rd = (vec_ref[j:j + 1, :] for j in range(5))
+    act = act != 0
 
     # ---- replica probe (first-match way + protocol.valid)
-    eq1 = rp_tag_ref[...] == addr[:, None]
-    th1 = eq1.any(axis=-1)
-    way1 = jnp.argmax(eq1, axis=-1).astype(i32)
-    h1 = th1 & (cts1_ref[...] <= _first_match(eq1, rp_rts_ref[...]))
+    th1, way1, eq1 = _probe(rp_tag_ref[...], addr)
+    h1 = th1 & (cts1 <= at_first(eq1, rp_rts_ref[...]))
     th1, h1 = th1 & act, h1 & act
     miss = act & ~h1
 
     # ---- shared probe (only meaningful on a replica miss)
-    eq2 = sh_tag_ref[...] == addr[:, None]
-    th2 = eq2.any(axis=-1)
-    way2 = jnp.argmax(eq2, axis=-1).astype(i32)
-    rts2 = _first_match(eq2, sh_rts_ref[...])
-    wts2 = _first_match(eq2, sh_wts_ref[...])
-    h2 = th2 & (cts2_ref[...] <= rts2)
+    th2, way2, eq2 = _probe(sh_tag_ref[...], addr)
+    rts2 = at_first(eq2, sh_rts_ref[...])
+    wts2 = at_first(eq2, sh_wts_ref[...])
+    h2 = th2 & (cts2 <= rts2)
     th2, h2 = th2 & miss, h2 & miss
     need = miss & ~h2
 
     # ---- TSU read grant (Algorithm 3 + 16-bit overflow reinit)
-    eqt = ts_tag_ref[...] == addr[:, None]
-    tht = eqt.any(axis=-1)
-    tway = jnp.argmax(eqt, axis=-1).astype(i32)
-    memts = jnp.where(tht, _first_match(eqt, ts_mem_ref[...]), 0)
-    rd = rd_ref[...]
+    tht, tway, eqt = _probe(ts_tag_ref[...], addr)
+    memts = at_first(eqt, ts_mem_ref[...])        # 0 when absent
     mwts = memts                                  # protocol.mm_read
     mrts = memts + rd
     nmem = mrts
@@ -103,46 +83,43 @@ def _miss_round_kernel(rp_tag_ref, rp_rts_ref, sh_tag_ref, sh_rts_ref,
     fnd = need & tht
 
     # ---- response chain: install at shared, then at the replica
-    nwa = jnp.maximum(cts2_ref[...], mwts)        # protocol.install
+    nwa = jnp.maximum(cts2, mwts)                 # protocol.install
     nra = jnp.maximum(nwa + 1, mrts)
     rwts = jnp.where(h2, wts2, nwa)
     rrts = jnp.where(h2, rts2, nra)
-    nw1 = jnp.maximum(cts1_ref[...], rwts)
+    nw1 = jnp.maximum(cts1, rwts)
     nr1 = jnp.maximum(nw1 + 1, rrts)
 
-    for ref, v in ((th1_ref, th1), (h1_ref, h1), (th2_ref, th2),
-                   (h2_ref, h2), (fnd_ref, fnd), (ovf_ref, fnd & ovf)):
-        ref[...] = v.astype(i32)
-    for ref, v in ((way1_ref, way1), (way2_ref, way2), (tway_ref, tway),
-                   (mwts_ref, mwts), (mrts_ref, mrts), (nmem_ref, nmem),
-                   (nwa_ref, nwa), (nra_ref, nra), (nw1_ref, nw1),
-                   (nr1_ref, nr1)):
-        ref[...] = v
-
-
-def _write_grant_kernel(ts_tag_ref, ts_mem_ref, ts_seq_ref, addr_ref,
-                        wl_ref, th_ref, way_ref, full_ref, wts_ref,
-                        rts_ref, nmem_ref, ovf_ref):
     i32 = jnp.int32
-    addr = addr_ref[...]
+    for j, v in enumerate((th1.astype(i32), h1.astype(i32), way1,
+                           th2.astype(i32), h2.astype(i32), way2,
+                           fnd.astype(i32), tway, mwts, mrts, nmem,
+                           (fnd & ovf).astype(i32), nwa, nra, nw1, nr1)):
+        out_ref[j:j + 1, :] = v
+
+
+def _write_grant_kernel(ts_tag_ref, ts_mem_ref, ts_seq_ref, vec_ref,
+                        out_ref):
+    i32 = jnp.int32
+    addr, wl = vec_ref[0:1, :], vec_ref[1:2, :]
     tags = ts_tag_ref[...]
     mem = ts_mem_ref[...]
 
-    eq = tags == addr[:, None]
-    th = eq.any(axis=-1)
-    way = jnp.argmax(eq, axis=-1).astype(i32)
+    th, way, eq = _probe(tags, addr)
     # lexicographic victim: invalid first, else min memts, ties broken by
-    # min alloc seq (state.victim_lex — the host dict-order rule)
+    # min alloc seq (state.victim_lex — the host dict-order rule); the
+    # first such way, as argmin picks it
     invalid = tags == _INVALID
     p = jnp.where(invalid, i32(_NEG), mem)
-    pmin = jnp.min(p, axis=-1, keepdims=True)
+    pmin = jnp.min(p, axis=0, keepdims=True)
     s = jnp.where(p == pmin, ts_seq_ref[...], i32(2 ** 30))
-    vic = jnp.argmin(s, axis=-1).astype(i32)
+    smin = jnp.min(s, axis=0, keepdims=True)
+    vic, _ = first_index(s == smin)
     w0 = jnp.where(th, way, vic)
-    full = (~invalid).all(axis=-1)
+    n_valid = jnp.sum((~invalid).astype(i32), axis=0, keepdims=True)
+    full = n_valid == tags.shape[0]
 
-    memts = jnp.where(th, _first_match(eq, mem), 0)
-    wl = wl_ref[...]
+    memts = at_first(eq, mem)                     # 0 when absent
     wts = memts + 1                               # protocol.mm_write
     rts = memts + wl
     nmem = rts
@@ -151,31 +128,14 @@ def _write_grant_kernel(ts_tag_ref, ts_mem_ref, ts_seq_ref, addr_ref,
     rts = jnp.where(ovf, wl, rts)
     nmem = jnp.where(ovf, rts, nmem)
 
-    th_ref[...] = th.astype(i32)
-    way_ref[...] = w0
-    full_ref[...] = full.astype(i32)
-    wts_ref[...] = wts
-    rts_ref[...] = rts
-    nmem_ref[...] = nmem
-    ovf_ref[...] = ovf.astype(i32)
+    for j, v in enumerate((th.astype(i32), w0, full.astype(i32), wts, rts,
+                           nmem, ovf.astype(i32))):
+        out_ref[j:j + 1, :] = v
 
 
-def _grid(N, bn):
-    bn = min(bn, N)
-    while N % bn:
-        bn -= 1
-    return (N // bn,), bn
-
-
-def _interp(interpret):
-    if interpret is None:
-        return jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm")
-    return interpret
-
-
-@functools.partial(jax.jit, static_argnames=("bn", "interpret"))
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def miss_round(rp_tag, rp_rts, sh_tag, sh_rts, sh_wts, ts_tag, ts_mem,
-               cts1, cts2, addr, act, rd, *, bn=256, interpret=None):
+               cts1, cts2, addr, act, rd, *, interpret=None):
     """Fused read-side round math over gathered set rows.
 
     rp_tag/rp_rts: [N, W1] live replica-set ways; sh_tag/sh_rts/sh_wts:
@@ -194,33 +154,18 @@ def miss_round(rp_tag, rp_rts, sh_tag, sh_rts, sh_wts, ts_tag, ts_mem,
       nw1/nr1         — install at the replica of the response lease
                         (shared hit's lease when h2, else nwa/nra)
     """
-    interpret = _interp(interpret)
-    N, W1 = rp_tag.shape
-    W2 = sh_tag.shape[1]
-    C = ts_tag.shape[1]
-    grid, bn = _grid(N, bn)
-    row = lambda W: pl.BlockSpec((bn, W), lambda i: (i, 0))
-    vec = pl.BlockSpec((bn,), lambda i: (i,))
-    outs = pl.pallas_call(
-        _miss_round_kernel,
-        grid=grid,
-        in_specs=[row(W1), row(W1), row(W2), row(W2), row(W2), row(C),
-                  row(C), vec, vec, vec, vec, vec],
-        out_specs=[vec] * 16,
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.int32)] * 16,
-        interpret=interpret,
-    )(rp_tag, rp_rts, sh_tag, sh_rts, sh_wts, ts_tag, ts_mem, cts1, cts2,
-      addr, act, rd)
+    out = lane_call(_miss_round_kernel,
+                    [rp_tag, rp_rts, sh_tag, sh_rts, sh_wts, ts_tag, ts_mem],
+                    [cts1, cts2, addr, act, rd], _MISS_OUTS, interpret)
     b = lambda x: x.astype(bool)
     (th1, h1, way1, th2, h2, way2, fnd, tway, mwts, mrts, nmem, ovf, nwa,
-     nra, nw1, nr1) = outs
+     nra, nw1, nr1) = out
     return (b(th1), b(h1), way1, b(th2), b(h2), way2, b(fnd), tway, mwts,
             mrts, nmem, b(ovf), nwa, nra, nw1, nr1)
 
 
-@functools.partial(jax.jit, static_argnames=("bn", "interpret"))
-def write_grant(ts_tag, ts_mem, ts_seq, addr, wl, *, bn=256,
-                interpret=None):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def write_grant(ts_tag, ts_mem, ts_seq, addr, wl, *, interpret=None):
     """Fused write-side TSU math over gathered shard rows.
 
     ts_tag/ts_mem/ts_seq: [N, C] the TSU shard's live ways (tag, entry
@@ -233,19 +178,8 @@ def write_grant(ts_tag, ts_mem, ts_seq, addr, wl, *, bn=256,
       wts/rts/nmem/ovf — ``mm_write`` grant + overflow reinit (raw;
       inactive-lane masking is the caller's).
     """
-    interpret = _interp(interpret)
-    N, C = ts_tag.shape
-    grid, bn = _grid(N, bn)
-    row = pl.BlockSpec((bn, C), lambda i: (i, 0))
-    vec = pl.BlockSpec((bn,), lambda i: (i,))
-    outs = pl.pallas_call(
-        _write_grant_kernel,
-        grid=grid,
-        in_specs=[row, row, row, vec, vec],
-        out_specs=[vec] * 7,
-        out_shape=[jax.ShapeDtypeStruct((N,), jnp.int32)] * 7,
-        interpret=interpret,
-    )(ts_tag, ts_mem, ts_seq, addr, wl)
-    th, way, full, wts, rts, nmem, ovf = outs
+    out = lane_call(_write_grant_kernel, [ts_tag, ts_mem, ts_seq],
+                    [addr, wl], _WRITE_OUTS, interpret)
+    th, way, full, wts, rts, nmem, ovf = out
     return (th.astype(bool), way, full.astype(bool), wts, rts, nmem,
             ovf.astype(bool))

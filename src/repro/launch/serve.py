@@ -16,6 +16,7 @@ import numpy as np
 
 from repro import configs as cfgs
 from repro.coherence.fabric import FabricConfig, default_fabric
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_model
 from repro.runtime.server import Request, Server
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--rd-lease", type=int, default=8)
     ap.add_argument("--wr-lease", type=int, default=4)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = cfgs.SMOKE[args.arch]            # serving demo runs the smoke cfg
     params = init_model(cfg, jax.random.PRNGKey(0))

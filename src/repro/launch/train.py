@@ -10,6 +10,7 @@ import argparse
 
 from repro import configs as cfgs
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.runtime.trainer import Trainer, TrainerConfig
 
@@ -26,6 +27,7 @@ def main():
     ap.add_argument("--mesh", default="host",
                     choices=["host", "single", "multi"])
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = cfgs.SMOKE[args.arch] if args.smoke else cfgs.get(args.arch)
     mesh = (make_host_mesh() if args.mesh == "host" else

@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 from repro.models.layers import swiglu
 from repro.models.params import P
-import repro.sharding as sharding
 from repro.sharding import NOSHARD
 
 
@@ -205,7 +204,7 @@ def _moe_shard_map(cfg: ModelConfig, p: dict, h, ctx):
         return out.reshape(x.shape), aux
 
     x_spec = P(dp_axes if len(dp_axes) > 1 else dp_axes[0], "model", None)
-    out, aux = sharding.shard_map(
+    out, aux = jax.shard_map(
         local, mesh=mesh,
         in_specs=(x_spec, r_spec, wg_spec, wg_spec, wo_spec),
         out_specs=(x_spec, P()),

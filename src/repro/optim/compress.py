@@ -16,8 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-import repro.sharding as sharding
-
 
 def quantize(x, axis=None) -> Tuple[jnp.ndarray, jnp.ndarray]:
     scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 127.0 + 1e-12
@@ -70,8 +68,8 @@ def make_compressed_allreduce(mesh, dp_axes=("data",)):
     def ar(x):
         def inner(xs):
             return compressed_psum(xs, axis, n)
-        return sharding.shard_map(inner, mesh=mesh, in_specs=P(),
-                                  out_specs=P(), axis_names=set(dp_axes),
-                                  check_vma=False)(x)
+        return jax.shard_map(inner, mesh=mesh, in_specs=P(),
+                             out_specs=P(), axis_names=set(dp_axes),
+                             check_vma=False)(x)
 
     return ar

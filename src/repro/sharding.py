@@ -41,22 +41,6 @@ def _axis_size(mesh: Mesh, name: str) -> int:
     return dict(zip(mesh.axis_names, mesh.devices.shape)).get(name, 1)
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, axis_names=None,
-              check_vma: bool = True):
-    """``jax.shard_map`` with a fallback for jax<0.5 (this container's
-    0.4.x), where the API lives in jax.experimental with ``auto``/
-    ``check_rep`` instead of ``axis_names``/``check_vma``."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, axis_names=axis_names,
-                             check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    auto = (frozenset(mesh.axis_names) - set(axis_names)
-            if axis_names is not None else frozenset())
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=check_vma, auto=auto)
-
-
 def mesh_axes_for(
     mesh: Mesh,
     dim_size: int,

@@ -10,11 +10,12 @@ import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 sys.path.insert(0, "src")
 import jax, json
+from repro.launch.mesh import auto_mesh
 from repro import configs as cfgs
 from repro.launch import steps as S
 from repro.launch import hloanalysis as H
 from repro.models.config import SHAPES
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = auto_mesh((4, 2), ("data", "model"))
 for arch, si in (("smollm-360m", 0), ("mamba2-130m", 3)):
     cfg = cfgs.get(arch)
     cell = SHAPES[si]

@@ -190,12 +190,8 @@ def test_lease_probe_duplicate_tags_use_first_way():
     np.testing.assert_array_equal(np.asarray(got[1]), [False, True, False])
 
 
-@pytest.mark.parametrize("interpret", [
-    True,
-    pytest.param(False, marks=pytest.mark.skipif(
-        jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm"),
-        reason="compiled Pallas needs a TPU/GPU backend")),
-])
+# compiled (Mosaic) cases of the lease kernels: tests/test_tpu_compile.py
+@pytest.mark.parametrize("interpret", [True])
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_lease_probe_matches_protocol(interpret, seed):
     """Bit-for-bit parity of the kernel's install math against
@@ -243,12 +239,8 @@ _MISS_OUTS = ["th1", "h1", "way1", "th2", "h2", "way2", "fnd", "tway",
 _WAYS = {"way1", "way2", "tway"}           # meaningful only on a tag hit
 
 
-@pytest.mark.parametrize("interpret", [
-    True,
-    pytest.param(False, marks=pytest.mark.skipif(
-        jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm"),
-        reason="compiled Pallas needs a TPU/GPU backend")),
-])
+# compiled (Mosaic) cases of the lease kernels: tests/test_tpu_compile.py
+@pytest.mark.parametrize("interpret", [True])
 @pytest.mark.parametrize("N,W1,W2,C,seed", [
     (64, 4, 8, 16, 0), (256, 2, 4, 64, 1), (96, 8, 2, 8, 2)])
 def test_miss_round_kernel(interpret, N, W1, W2, C, seed):
@@ -312,12 +304,8 @@ def test_miss_round_matches_state_rules(seed):
     assert not (fnd & np.asarray(h2)).any()
 
 
-@pytest.mark.parametrize("interpret", [
-    True,
-    pytest.param(False, marks=pytest.mark.skipif(
-        jax.default_backend() not in ("tpu", "gpu", "cuda", "rocm"),
-        reason="compiled Pallas needs a TPU/GPU backend")),
-])
+# compiled (Mosaic) cases of the lease kernels: tests/test_tpu_compile.py
+@pytest.mark.parametrize("interpret", [True])
 @pytest.mark.parametrize("N,C,seed", [(64, 16, 0), (256, 64, 1), (40, 8, 2)])
 def test_write_grant_kernel(interpret, N, C, seed):
     """The fused write-side TSU kernel (probe + lexicographic victim +

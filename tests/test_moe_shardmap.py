@@ -21,7 +21,8 @@ import jax, jax.numpy as jnp, numpy as np
 from repro import configs as cfgs
 from repro.models import moe as moe_mod
 from repro.sharding import ShardCtx, NOSHARD
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+from repro.launch.mesh import auto_mesh
+mesh = auto_mesh((2, 4), ("data", "model"))
 ctx = ShardCtx(mesh)
 cfg = dataclasses.replace(cfgs.SMOKE["deepseek-v2-236b"], n_experts=8,
                           top_k=2, capacity_factor=8.0)  # no drops => equal
@@ -34,7 +35,7 @@ o2, a2 = jax.jit(lambda p, h: moe_mod._moe_shard_map(cfg, p, h, ctx))(p, h)
 np.testing.assert_allclose(np.asarray(ref), np.asarray(o2), rtol=2e-4, atol=2e-4)
 np.testing.assert_allclose(float(aref), float(a2), rtol=0.3)  # aux: local approx
 # single-mesh-axis GSPMD runs are NOT hit by the partitioner bug; pin that
-mesh1 = jax.make_mesh((1, 8), ("data", "model"))
+mesh1 = auto_mesh((1, 8), ("data", "model"))
 o1, a1 = jax.jit(lambda p, h: moe_mod._moe_gspmd(cfg, p, h, ShardCtx(mesh1)))(p, h)
 np.testing.assert_allclose(np.asarray(ref), np.asarray(o1), rtol=2e-4, atol=2e-4)
 print("MOE_MATCH_OK")

@@ -222,8 +222,6 @@ def test_jaxpr_collectives_counts_loop_bodies():
     import jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec
 
-    from repro.sharding import shard_map
-
     def body(c, x):
         return c + jax.lax.psum(x, "i"), x
 
@@ -233,8 +231,8 @@ def test_jaxpr_collectives_counts_loop_bodies():
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("i",))
     jaxpr = jax.make_jaxpr(
-        shard_map(fn, mesh, in_specs=PartitionSpec("i"),
-                  out_specs=PartitionSpec(), check_vma=False)
+        jax.shard_map(fn, mesh=mesh, in_specs=PartitionSpec("i"),
+                      out_specs=PartitionSpec(), check_vma=False)
     )(jnp.ones((8,), jnp.float32))
     c = jaxpr_collectives(jaxpr)
     assert c["total"] == 2 and c["in_loop"] == 1 and c["loops"] >= 1

@@ -12,6 +12,7 @@ from repro.checkpoint.manager import CheckpointManager
 from repro.coherence.kv_lease import AuthoritativeStore, LeaseKVCache
 from repro.coherence.lease_sync import LeaseConfig, VmappedWorkers
 from repro.data.pipeline import DataConfig, SyntheticLM
+from repro.launch.mesh import auto_mesh
 from repro.optim import adamw
 from repro.optim.compress import dequantize, ef_compress, quantize
 from repro.runtime.server import Request, Server
@@ -57,7 +58,7 @@ def test_checkpoint_roundtrip_and_reshard(tmp_path):
     assert mgr.latest_step() == 30
     # keep=2 garbage-collects the oldest
     assert not (tmp_path / "step_00000010").exists()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     from repro.models import model_shardings
     psh = model_shardings(SMOKE, mesh)
     ssh = adamw.state_shardings(psh, mesh)
@@ -75,7 +76,7 @@ def micro_trainer_cfg():
 
 def test_trainer_checkpoint_restart(tmp_path, micro_trainer_cfg):
     cfg = micro_trainer_cfg
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     data = tiny_data(cfg)
     t = Trainer(cfg, mesh, tcfg=TrainerConfig(total_steps=8, ckpt_period=4,
                                               ckpt_dir=str(tmp_path)),
@@ -91,14 +92,14 @@ def test_trainer_checkpoint_restart(tmp_path, micro_trainer_cfg):
 
 def test_trainer_elastic_remesh(tmp_path, micro_trainer_cfg):
     cfg = micro_trainer_cfg
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     data = tiny_data(cfg)
     t = Trainer(cfg, mesh, tcfg=TrainerConfig(total_steps=6, ckpt_period=3,
                                               ckpt_dir=str(tmp_path)),
                 data=data)
     with pytest.raises(RuntimeError):
         t.run(fail_at=4)
-    new_mesh = jax.make_mesh((1, 1), ("data", "model"))  # "smaller" cluster
+    new_mesh = auto_mesh((1, 1), ("data", "model"))  # "smaller" cluster
     res = t.resume(mesh=new_mesh)
     assert res["final_step"] == 6
     assert any(e["kind"] == "elastic_remesh" for e in t.events)
