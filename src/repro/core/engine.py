@@ -205,17 +205,17 @@ def sweep(cfgs: Sequence[SystemConfig], ops, addrs):
     to per-cell ``simulate`` (tests/test_sweep.py asserts parity); the
     per-round read log is elided to keep the batch memory-light."""
     cfgs = list(cfgs)
-    ops = np.asarray(ops, np.int32)
-    addrs = np.asarray(addrs, np.int32)
-    if ops.ndim != 3:
-        raise ValueError(f"expected [B, NC, R] batch, got {ops.shape}")
-    B, NC, R = ops.shape
-    for c in cfgs:
-        if c.n_cus != NC:
-            raise ValueError(f"config {c.name} has n_cus={c.n_cus}, "
-                             f"traces have NC={NC}")
-    n_addr = _next_pow2(int(addrs.max()) + 2)
-    with obs.span("engine.sweep.pack", cat="engine", B=B, NC=NC):
+    with obs.span("engine.sweep.pack", cat="engine"):
+        ops = np.asarray(ops, np.int32)
+        addrs = np.asarray(addrs, np.int32)
+        if ops.ndim != 3:
+            raise ValueError(f"expected [B, NC, R] batch, got {ops.shape}")
+        B, NC, R = ops.shape
+        for c in cfgs:
+            if c.n_cus != NC:
+                raise ValueError(f"config {c.name} has n_cus={c.n_cus}, "
+                                 f"traces have NC={NC}")
+        n_addr = _next_pow2(int(addrs.max()) + 2)
         T = _next_pow2(R)
         if T != R:                           # pad with NOPs (no effect)
             pad = ((0, 0), (0, 0), (0, T - R))

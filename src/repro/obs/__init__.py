@@ -8,23 +8,21 @@ package participates in a coherence decision, and with tracing disabled
   * ``trace``    — a low-overhead host-side span tracer emitting
     Chrome-trace/Perfetto-compatible JSON; spans wrap every fabric batch
     lifecycle phase (pack → exchange → scan → miss pass → decode →
-    donate) plus the jit-dispatch vs device-execute split via
-    ``block_until_ready`` fencing.
-  * ``metrics`` / ``registry`` — log-bucketed latency histograms with
-    exact p50/p95/p99 summaries and a ``MetricsRegistry`` keyed by
-    (fabric, scenario) with snapshot/delta semantics over ``FabricStats``
-    counter blocks.
+    donate) and every scheduler wave phase, plus the jit-dispatch vs
+    device-execute split via ``block_until_ready`` fencing; while enabled
+    each span is also a ``jax.profiler.TraceAnnotation``.
+  * ``metrics``  — log-bucketed latency histograms with exact
+    p50/p95/p99 summaries.
   * ``xprof``    — static cost probes: a jaxpr walker counting collectives
     (the generalization of ``pipeline.collective_counts``) plus compiled
     cost analysis (FLOPs, bytes accessed) per fabric function.
 """
 from repro.obs.metrics import LatencyHistogram
-from repro.obs.registry import MetricsRegistry
 from repro.obs.trace import Tracer, disable, enable, get_tracer, set_tracer
 from repro.obs.xprof import cost_probe, jaxpr_collectives
 
 __all__ = [
-    "LatencyHistogram", "MetricsRegistry", "Tracer",
+    "LatencyHistogram", "Tracer",
     "enable", "disable", "get_tracer", "set_tracer",
     "cost_probe", "jaxpr_collectives",
 ]

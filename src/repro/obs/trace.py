@@ -3,8 +3,13 @@
 The tracer wraps the fabric batch lifecycle phases (DESIGN.md §10 span
 taxonomy: ``fabric.pack`` → ``fabric.exchange`` → ``fabric.scan`` /
 ``fabric.fast_probe`` → ``fabric.miss_pass`` → ``fabric.decode`` →
-``fabric.donate``, plus ``serve.*`` and ``engine.sweep.*``) and exports
-them as Chrome-trace JSON — openable in ``chrome://tracing`` / Perfetto.
+``fabric.donate``, plus ``sched.*``, ``serve.*`` and ``engine.sweep.*``)
+and exports them as Chrome-trace JSON — openable in ``chrome://tracing``
+/ Perfetto.  While enabled, every span also holds a
+``jax.profiler.TraceAnnotation`` of its name and arguments, so under a
+running ``jax.profiler`` trace the spans land on the host plane of the
+xplane, on the device trace's clock (a span open when the profiler
+starts or stops is not in that trace).
 
 Design constraints, in priority order:
 
@@ -39,7 +44,10 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
-__all__ = ["Tracer", "span", "fence", "instant", "enable", "disable",
+import jax
+from jax.profiler import TraceAnnotation
+
+__all__ = ["Tracer", "span", "fence", "enable", "disable",
            "get_tracer", "set_tracer", "disabled_span_cost_ns"]
 
 # one event = (name, cat, tid, t0_ns, dur_ns, depth, args)
@@ -62,9 +70,10 @@ _NULL_SPAN = _NullSpan()
 
 
 class _Span:
-    """One live span: records entry/exit timestamps on the tracer."""
+    """One live span: records entry/exit timestamps on the tracer and
+    holds a profiler annotation of the same name in between."""
 
-    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_depth")
+    __slots__ = ("_tr", "_name", "_cat", "_args", "_t0", "_depth", "_ann")
 
     def __init__(self, tr: "Tracer", name: str, cat: str,
                  args: Optional[Dict[str, Any]]):
@@ -74,6 +83,8 @@ class _Span:
         self._args = args
 
     def __enter__(self):
+        self._ann = TraceAnnotation(self._name, **(self._args or {}))
+        self._ann.__enter__()
         stack = self._tr._stack()
         self._depth = len(stack)
         stack.append(self)
@@ -82,6 +93,7 @@ class _Span:
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        self._ann.__exit__(None, None, None)
         popped = self._tr._stack().pop()
         assert popped is self, "span exits out of order"
         self._tr._events.append(
@@ -124,18 +136,9 @@ class Tracer:
         async dispatch)."""
         if not self.enabled:
             return value
-        import jax
         with _Span(self, name, cat, None):
             jax.block_until_ready(value)
         return value
-
-    def instant(self, name: str, cat: str = "fabric", **args) -> None:
-        """Zero-duration marker event."""
-        if not self.enabled:
-            return
-        t = time.perf_counter_ns()
-        self._events.append((name, cat, threading.get_ident(), t, 0,
-                             len(self._stack()), args or None))
 
     # ------------------------------------------------------------- views
     @property
@@ -228,12 +231,6 @@ def fence(value, name: str = "device_execute", cat: str = "device"):
     if not tr.enabled:
         return value
     return tr.fence(value, name, cat)
-
-
-def instant(name: str, cat: str = "fabric", **args) -> None:
-    tr = _tracer
-    if tr.enabled:
-        tr.instant(name, cat, **args)
 
 
 def disabled_span_cost_ns(iters: int = 20000) -> float:

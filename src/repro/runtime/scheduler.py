@@ -49,6 +49,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from repro.obs import trace as obs
 from repro.runtime.loadgen import RequestTrace
 
 
@@ -142,6 +143,15 @@ def replay(backend, trace: RequestTrace, policy: BatchPolicy, *,
     ``service_model(padded_size) -> seconds`` makes the virtual clock
     deterministic (fabric calls still execute; only their time charge is
     modeled).  Default: measured wall clock.
+
+    Spans (``obs.trace``, DESIGN.md §10a), per wave and never per
+    request: ``sched.replay`` is the root; ``sched.admit``, ``sched.form``
+    (fire decision, members, kids, bucket padding), ``sched.storm`` and
+    ``sched.dispatch`` (child ``sched.keys``) carry ``wave=``, the index
+    of the wave being formed, ``sched.resolve`` that of the wave it
+    resolves; the fabric's spans nest under them.  Spans sit around or
+    inside the ``timed`` calls and move no work across them, so the
+    virtual clock charges the same.
     """
     key_of = key_of or (lambda k: f"prefix/{k}")
     t_arr, kids, n = trace.t, trace.kid, len(trace)
@@ -171,13 +181,15 @@ def replay(backend, trace: RequestTrace, policy: BatchPolicy, *,
 
     def resolve_pending() -> None:
         nonlocal pending, now
-        members, handle = pending
-        w = timed(handle.result, 0.0)
-        now += w
-        walls["resolve_s"] += w
-        for r in members:
-            done[r] = now
-        pending = None
+        # the one handle outstanding is the last wave dispatched
+        with obs.span("sched.resolve", cat="sched", wave=n_waves - 1):
+            members, handle = pending
+            w = timed(handle.result, 0.0)
+            now += w
+            walls["resolve_s"] += w
+            for r in members:
+                done[r] = now
+            pending = None
 
     def try_fire() -> Optional[Tuple[List[int], str]]:
         if not q:
@@ -209,55 +221,65 @@ def replay(backend, trace: RequestTrace, policy: BatchPolicy, *,
             cands.append(t_arr[n - 1])           # last arrival → final drain
         return min(cands) if cands else None
 
-    while True:
-        admit()
-        fired = try_fire()
-        if fired is None:
-            if pending is not None:
-                resolve_pending()                # drain the in-flight wave
+    def dispatch(padded: List[int], holder: dict) -> None:
+        with obs.span("sched.keys", cat="sched", wave=n_waves):
+            keys = [key_of(k) for k in padded]
+        holder["h"] = backend.read_batch_async(keys, replica=replica)
+
+    with obs.span("sched.replay", cat="sched", n_requests=n):
+        while True:
+            with obs.span("sched.admit", cat="sched", wave=n_waves):
+                admit()
+            with obs.span("sched.form", cat="sched", wave=n_waves):
+                fired = try_fire()
+                if fired is not None:
+                    members, kind = fired
+                    fires[kind] += 1
+                    ks = [int(kids[r]) for r in members]
+                    padded = pad_to_bucket(ks, policy)
+            if fired is None:
+                if pending is not None:
+                    resolve_pending()            # drain the in-flight wave
+                    continue
+                nft = next_fire_time()
+                if nft is None:
+                    break
+                now = max(now, nft)              # idle: jump the clock
                 continue
-            nft = next_fire_time()
-            if nft is None:
-                break
-            now = max(now, nft)                  # idle: jump the clock
-            continue
-        members, kind = fired
-        fires[kind] += 1
-        if republish_every and served >= next_storm_at:
+            if republish_every and served >= next_storm_at:
+                if pending is not None:
+                    resolve_pending()            # handle before write/fence
+                with obs.span("sched.storm", cat="sched", wave=n_waves):
+                    sl = [(n_storms * republish_n + j)
+                          % trace.n_keys for j in range(republish_n)]
+                    w = timed(
+                        lambda: (backend.write_batch(
+                            [(key_of(k), f"v@{n_waves}") for k in sl],
+                            replica=writer), backend.fence()),
+                        service_model(len(sl)) if service_model else 0.0)
+                    now += w
+                    walls["republish_s"] += w
+                    events.append(("write", sl))
+                    events.append(("fence",))
+                    n_storms += 1
+                    next_storm_at += republish_every
             if pending is not None:
-                resolve_pending()                # handle before write/fence
-            sl = [(n_storms * republish_n + j)
-                  % trace.n_keys for j in range(republish_n)]
-            w = timed(
-                lambda: (backend.write_batch(
-                    [(key_of(k), f"v@{n_waves}") for k in sl],
-                    replica=writer), backend.fence()),
-                service_model(len(sl)) if service_model else 0.0)
-            now += w
-            walls["republish_s"] += w
-            events.append(("write", sl))
-            events.append(("fence",))
-            n_storms += 1
-            next_storm_at += republish_every
-        ks = [int(kids[r]) for r in members]
-        padded = pad_to_bucket(ks, policy)
+                resolve_pending()                # N resolves before N+1
+            with obs.span("sched.dispatch", cat="sched", wave=n_waves):
+                holder = {}
+                w = timed(
+                    lambda: dispatch(padded, holder),
+                    service_model(len(padded)) if service_model else 0.0)
+                now += w
+                walls["dispatch_s"] += w
+                events.append(("read", list(padded)))
+                batch_sizes.append(len(ks))
+                padded_sizes.append(len(padded))
+                pending = (members, holder["h"])
+                n_waves += 1
+                served += len(members)
         if pending is not None:
-            resolve_pending()                    # N resolves before N+1
-        holder = {}
-        w = timed(
-            lambda: holder.update(h=backend.read_batch_async(
-                [key_of(k) for k in padded], replica=replica)),
-            service_model(len(padded)) if service_model else 0.0)
-        now += w
-        walls["dispatch_s"] += w
-        events.append(("read", list(padded)))
-        batch_sizes.append(len(ks))
-        padded_sizes.append(len(padded))
-        pending = (members, holder["h"])
-        n_waves += 1
-        served += len(members)
-    if pending is not None:
-        resolve_pending()
+            resolve_pending()
 
     assert not np.isnan(done).any(), "replay lost requests"
     return ReplayResult(latency_s=done - t_arr, t_end=now,

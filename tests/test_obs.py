@@ -4,14 +4,14 @@ Four contracts:
 
   * histogram percentiles are numpy-exact while samples are retained and
     a sane bucket interpolation past the cap;
-  * the registry's snapshot/delta windows tile FabricStats counters
-    without gaps or double counting;
   * a REAL traced fabric batch exports schema-valid Chrome-trace JSON
     whose spans form a well-nested forest (strict stack discipline);
+  * enabled spans are mirrored into a running ``jax.profiler`` trace by
+    name, nested as they ran; disabled ones write nothing there;
   * the <1% gate: with tracing disabled (the default), the span
-    instrumentation left on the batched serving hot path costs under 1%
-    of a serving batch — the paper's own overhead bar (§6.2) applied to
-    our own telemetry.
+    instrumentation left on one replayed serving wave (scheduler and
+    fabric) costs under 1% of a serving batch — the paper's own overhead
+    bar (§6.2) applied to our own telemetry.
 """
 import json
 
@@ -19,7 +19,7 @@ import numpy as np
 import pytest
 
 from repro.coherence.fabric import ArrayFabric, FabricConfig
-from repro.obs import LatencyHistogram, MetricsRegistry
+from repro.obs import LatencyHistogram
 from repro.obs import trace as obs_trace
 from repro.obs.xprof import cost_probe, jaxpr_collectives
 
@@ -67,38 +67,6 @@ def test_histogram_validation_and_merge():
     assert a.count == 3 and a.max_s == 4e-3
     with pytest.raises(ValueError):
         a.merge(LatencyHistogram(base=1e-3))
-
-
-# --------------------------------------------------------------- registry
-def test_registry_deltas_tile_the_counter_timeline():
-    reg = MetricsRegistry()
-    key = ("fabric", "shared_prefix")
-    reg.snapshot(key, {"reads": 10, "writes": 2})
-    d1 = reg.delta(key, {"reads": 25, "writes": 2})
-    assert d1 == {"reads": 15, "writes": 0}
-    d2 = reg.delta(key, {"reads": 30, "writes": 7})   # advanced: no overlap
-    assert d2 == {"reads": 5, "writes": 5}
-    # advance=False peeks without moving the window
-    d3 = reg.delta(key, {"reads": 31, "writes": 7}, advance=False)
-    d4 = reg.delta(key, {"reads": 31, "writes": 7})
-    assert d3 == d4 == {"reads": 1, "writes": 0}
-    # a key with no snapshot diffs against zero
-    assert reg.delta(("other",), {"reads": 3}) == {"reads": 3}
-
-
-def test_registry_accepts_fabric_backends_and_summarizes():
-    fab = ArrayFabric(FabricConfig(n_shards=2, rd_lease=4, wr_lease=2))
-    reg = MetricsRegistry()
-    key = ("array", "smoke")
-    reg.snapshot(key, fab)                         # .stats() surface
-    fab.write("k", "v")
-    fab.read("k")
-    d = reg.delta(key, fab)
-    assert d["reads"] == 1 and d["writes"] == 1
-    reg.observe(key, "total", 2e-3)
-    s = reg.summary()["array/smoke"]
-    assert s["latency"]["total"]["count"] == 1
-    assert s["counters"]["reads"] == fab.stats()["reads"]
 
 
 # ------------------------------------------------------- trace well-formed
@@ -172,18 +140,64 @@ def test_disabled_tracing_records_nothing_and_passes_values():
             pass
         sentinel = object()
         assert obs_trace.fence(sentinel) is sentinel
-        obs_trace.instant("y")
     finally:
         obs_trace.set_tracer(old)
     assert tr.events == []
 
 
+# ------------------------------------------------ mirrored into the profiler
+def _profiled_host_events(tmp_path, enabled):
+    """Run one span with a fenced child under a CPU ``jax.profiler``
+    trace; returns the host-plane events named ``obs_test.*`` as (line,
+    name, start_ns, end_ns, stats)."""
+    import glob
+
+    import jax
+    import jax.numpy as jnp
+
+    tr = obs_trace.Tracer(enabled=enabled)
+    old = obs_trace.set_tracer(tr)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with obs_trace.span("obs_test.outer", wave=7):
+            obs_trace.fence(jnp.arange(8) + 1, "obs_test.outer.device")
+    finally:
+        jax.profiler.stop_trace()
+        obs_trace.set_tracer(old)
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"),
+                        recursive=True)
+    pd = jax.profiler.ProfileData.from_file(path)
+    return [(line.name, e.name, e.start_ns, e.start_ns + e.duration_ns,
+             dict(e.stats))
+            for plane in pd.planes if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith("obs_test.")]
+
+
+def test_enabled_spans_land_nested_on_the_profiler_host_plane(tmp_path):
+    evs = {name: (line, s, e, stats) for line, name, s, e, stats
+           in _profiled_host_events(tmp_path, enabled=True)}
+    assert set(evs) == {"obs_test.outer", "obs_test.outer.device"}
+    line, s, e, stats = evs["obs_test.outer"]
+    cline, cs, ce, _ = evs["obs_test.outer.device"]
+    assert cline == line and s <= cs and ce <= e and cs < ce
+    assert stats == {"wave": 7}               # the span's arguments
+
+
+def test_disabled_tracer_writes_no_profiler_annotation(tmp_path):
+    assert _profiled_host_events(tmp_path, enabled=False) == []
+
+
 # --------------------------------------------------------- <1% overhead gate
 def test_disabled_overhead_under_one_percent_of_serving_batch():
-    """The acceptance gate: spans-per-batch on the batched serving path
-    x the measured cost of one DISABLED span < 1% of the batch's p50.
-    (Methodology in DESIGN.md §10 — the uninstrumented build no longer
+    """The acceptance gate: the spans of one replayed serving wave
+    (``scheduler.replay``'s and the fabric's) x the measured cost of one
+    DISABLED span < 1% of the batched serving path's p50.
+    (Methodology in DESIGN.md §10d — the uninstrumented build no longer
     exists to A/B against, and this decomposition is noise-immune.)"""
+    from repro.runtime import scheduler
+    from repro.runtime.loadgen import RequestTrace
+
     cfg = FabricConfig(n_shards=4, rd_lease=64, wr_lease=4,
                        replica_sets=512, replica_ways=8,
                        shared_sets=1024, shared_ways=8)
@@ -199,15 +213,23 @@ def test_disabled_overhead_under_one_percent_of_serving_batch():
         fab.read_batch(hot, replica=1)             # all-hit steady state
         h.record(time.perf_counter() - t0)
     p50_us = h.summary()["p50_us"]
-    # count the spans this exact path executes
+    # count the spans of this batch replayed as one scheduler wave: the
+    # root, the end-of-stream drain and the fabric's spans included
+    wave = RequestTrace(t=np.zeros(len(hot)),
+                        kid=np.arange(len(hot), dtype=np.int32),
+                        n_keys=len(hot))
+    policy = scheduler.BatchPolicy(max_batch=len(hot))
     tr = obs_trace.Tracer(enabled=True)
     old = obs_trace.set_tracer(tr)
     try:
-        fab.read_batch(hot, replica=1)
+        res = scheduler.replay(fab, wave, policy)
     finally:
         obs_trace.set_tracer(old)
-    spans = len(tr.events)
-    assert spans >= 4                              # pack/probe/donate/decode
+    assert res.batch_sizes == [len(hot)]
+    names = [e[0] for e in tr.events]
+    assert {"sched.dispatch", "fabric.pack", "fabric.fast_probe",
+            "fabric.donate", "fabric.decode"} <= set(names)
+    spans = len(names)
     span_ns = obs_trace.disabled_span_cost_ns()
     overhead_pct = 100.0 * (spans * span_ns / 1e3) / p50_us
     assert overhead_pct < 1.0, (

@@ -5,6 +5,7 @@ import numpy as np
 import pytest
 
 from repro.coherence.fabric import ArrayFabric, FabricConfig
+from repro.obs import trace as obs_trace
 from repro.runtime import scheduler
 from repro.runtime.loadgen import RequestTrace, synthesize
 from repro.runtime.scheduler import (BatchPolicy, form_waves, pad_to_bucket,
@@ -221,3 +222,103 @@ def test_replay_against_array_fabric():
     # the event stream replays the same reads the fabric saw
     n_read_rows = sum(len(e[1]) for e in res.events if e[0] == "read")
     assert n_read_rows == sum(res.padded_sizes)
+
+
+# -------------------------------------------------------------------- spans
+def _traced(fn):
+    """Run ``fn`` under an enabled scoped tracer: (result, span events)."""
+    tr = obs_trace.Tracer(enabled=True)
+    old = obs_trace.set_tracer(tr)
+    try:
+        return fn(), tr.events
+    finally:
+        obs_trace.set_tracer(old)
+
+
+def _parents(events):
+    """(name, direct parent's name or None) for every span event."""
+    by_tid = {}
+    for ev in events:
+        by_tid.setdefault(ev[2], []).append(ev)
+    out = []
+    for evs in by_tid.values():
+        evs.sort(key=lambda e: (e[3], e[5]))
+        stack = []
+        for name, _cat, _tid, _t0, _dur, depth, _args in evs:
+            del stack[depth:]
+            out.append((name, stack[-1] if stack else None))
+            stack.append(name)
+    return out
+
+
+def test_one_dispatch_span_per_wave_with_its_wave_number():
+    tr = synthesize(200, 16, process="poisson", rate=100.0, seed=3)
+    pol = BatchPolicy(max_batch=32, max_wait_s=20e-3, min_bucket=8)
+    res, events = _traced(lambda: replay(
+        FakeBackend(), tr, pol, service_model=SVC, republish_every=50,
+        republish_n=4))
+    waves = list(range(len(res.batch_sizes)))
+    wave_of = lambda name: [e[6]["wave"] for e in events if e[0] == name]
+    assert len(waves) > 4
+    assert wave_of("sched.dispatch") == waves
+    assert wave_of("sched.keys") == waves
+    assert sorted(wave_of("sched.resolve")) == waves
+    assert len(wave_of("sched.storm")) == \
+        sum(e[0] == "fence" for e in res.events) == 4
+    assert [e[6] for e in events if e[0] == "sched.replay"] == \
+        [{"n_requests": 200}]
+    assert all(e[1] == "sched" for e in events)
+
+
+def test_span_count_grows_with_waves_not_requests():
+    def spans(n_requests, max_batch):
+        tr = mk_trace(np.zeros(n_requests), n_keys=8)
+        pol = BatchPolicy(max_batch=max_batch, min_bucket=8)
+        res, events = _traced(lambda: replay(FakeBackend(), tr, pol,
+                                             service_model=SVC))
+        return len(res.batch_sizes), len(events)
+
+    four_small, four_large, eight = spans(32, 8), spans(256, 64), \
+        spans(64, 8)
+    assert four_small[0] == four_large[0] == 4 and eight[0] == 8
+    assert four_small[1] == four_large[1] < eight[1]
+
+
+def _served_fabric():
+    fab = ArrayFabric(FabricConfig(**SMALL), n_nodes=1, replicas_per_node=2)
+    fab.write_batch([(f"prefix/{k}", "v@init") for k in range(8)],
+                    replica=0)
+    fab.fence()
+    return fab
+
+
+def _fabric_replay(fab):
+    tr = synthesize(60, 8, process="poisson", rate=500.0, seed=6)
+    pol = BatchPolicy(max_batch=8, max_wait_s=2e-3, min_bucket=8)
+    return replay(fab, tr, pol, republish_every=16, republish_n=4,
+                  service_model=lambda b: 1e-4 * b)
+
+
+def test_fabric_spans_nest_under_scheduler_spans():
+    fab = _served_fabric()
+    _, events = _traced(lambda: _fabric_replay(fab))
+    pairs = _parents(events)
+    fabric_roots = {p for name, p in pairs if name.startswith("fabric.")
+                    and not p.startswith("fabric.")}
+    # a wave with misses decodes its miss pass when the handle resolves
+    assert {"sched.dispatch", "sched.storm"} <= fabric_roots <= \
+        {"sched.dispatch", "sched.storm", "sched.resolve"}
+    assert {p for name, p in pairs if name.startswith("sched.")} == \
+        {None, "sched.replay", "sched.dispatch"}
+    assert [name for name, p in pairs if p is None] == ["sched.replay"]
+
+
+def test_replay_result_identical_traced_and_untraced():
+    plain = _fabric_replay(_served_fabric())
+    fab = _served_fabric()
+    traced, events = _traced(lambda: _fabric_replay(fab))
+    assert events
+    assert np.array_equal(plain.latency_s, traced.latency_s)
+    for field in ("t_end", "batch_sizes", "padded_sizes", "fires", "walls",
+                  "events"):
+        assert getattr(plain, field) == getattr(traced, field), field
