@@ -8,7 +8,11 @@ tiny batches that waste the one-collective grant pipeline.  This module
 replaces that with **admit-by-deadline** formation driven by a
 ``loadgen.RequestTrace``'s arrival timestamps:
 
-  * requests accumulate in an arrival queue;
+  * requests accumulate in an arrival queue, held as the index range
+    ``[head, i)`` of the trace: arrivals never go back in time and are
+    served in order, so admission, wave formation and completion
+    stamping are whole-range numpy operations, never a loop over
+    requests;
   * a wave fires when it reaches ``max_batch`` (full fire) OR when the
     oldest queued request has waited ``max_wait_s`` (deadline fire) —
     under ``mode="fixed"`` only full fires happen (plus one final
@@ -87,14 +91,18 @@ class BatchPolicy:
             raise ValueError("max_batch must be >= 1")
 
 
+def _bucket(b: int, policy: BatchPolicy) -> int:
+    """The probed size of a ``b``-request wave under ``policy``."""
+    return max(policy.min_bucket, _next_pow2(b)) if policy.bucket else b
+
+
 def pad_to_bucket(keys: Sequence, policy: BatchPolicy) -> List:
     """Pad a formed wave to its pow2 shape bucket by cycling the wave's
     own keys (no new keys → no spurious compulsory misses); callers
     discard the pad rows' results."""
-    if not policy.bucket or not keys:
+    if not keys:
         return list(keys)
-    m = max(policy.min_bucket, _next_pow2(len(keys)))
-    return [keys[j % len(keys)] for j in range(m)]
+    return [keys[j % len(keys)] for j in range(_bucket(len(keys), policy))]
 
 
 @dataclasses.dataclass
@@ -129,6 +137,17 @@ def replay(backend, trace: RequestTrace, policy: BatchPolicy, *,
            ) -> ReplayResult:
     """Replay ``trace`` open-loop against a ``FabricBackend``.
 
+    The arrival queue is an index range, never a container: arrivals are
+    nondecreasing and served in order, so the queued requests are always
+    ``[head, i)`` — ``head`` the oldest unserved, ``i`` the next not yet
+    admitted.  Admission is one ``searchsorted`` of the clock (which
+    never goes back), a wave is the slice ``[head, head + take)``, and
+    its completion stamp one slice store: the work per wave is a few
+    numpy calls, none per request.  ``key_of`` runs once per distinct
+    key id (a Python ``int``) and each wave gathers its strings from
+    that table, so every wave hands the backend the same ``str`` objects
+    for the same keys.
+
     A model-refresh write storm (``republish_n`` keys round-robin) +
     fence precedes the first wave and then every ``republish_every``
     SERVED REQUESTS — the outstanding read handle resolves first
@@ -146,20 +165,35 @@ def replay(backend, trace: RequestTrace, policy: BatchPolicy, *,
 
     Spans (``obs.trace``, DESIGN.md §10a), per wave and never per
     request: ``sched.replay`` is the root; ``sched.admit``, ``sched.form``
-    (fire decision, members, kids, bucket padding), ``sched.storm`` and
-    ``sched.dispatch`` (child ``sched.keys``) carry ``wave=``, the index
-    of the wave being formed, ``sched.resolve`` that of the wave it
-    resolves; the fabric's spans nest under them.  Spans sit around or
-    inside the ``timed`` calls and move no work across them, so the
+    (fire decision, the wave's slice, bucket padding), ``sched.storm``
+    and ``sched.dispatch`` (child ``sched.keys``) carry ``wave=``, the
+    index of the wave being formed, ``sched.resolve`` that of the wave
+    it resolves; the fabric's spans nest under them.  Spans sit around
+    or inside the ``timed`` calls and move no work across them, so the
     virtual clock charges the same.
     """
     key_of = key_of or (lambda k: f"prefix/{k}")
-    t_arr, kids, n = trace.t, trace.kid, len(trace)
-    q: collections.deque = collections.deque()   # admitted request indices
+    t_arr, kids, n = np.asarray(trace.t), np.asarray(trace.kid), len(trace)
+    if n and np.any(t_arr[1:] < t_arr[:-1]):
+        raise ValueError("arrival timestamps must be nondecreasing")
+    names: Dict[int, str] = {}                   # key id -> its string
+
+    def name(k: int) -> str:
+        s = names.get(k)
+        if s is None:
+            s = names[k] = key_of(k)
+        return s
+
+    # one slot per id up to the largest, a string only where it occurs
+    counts = np.bincount(kids)
+    key_str = np.empty(len(counts), object)
+    for k in np.flatnonzero(counts).tolist():
+        key_str[k] = name(k)
+    head = 0                                     # oldest unserved request
     i = 0                                        # next unadmitted arrival
     now = 0.0
     done = np.full(n, np.nan)
-    pending: Optional[Tuple[List[int], object]] = None
+    pending: Optional[Tuple[int, int, object]] = None
     events: List[Tuple] = []
     batch_sizes: List[int] = []
     padded_sizes: List[int] = []
@@ -175,55 +209,52 @@ def replay(backend, trace: RequestTrace, policy: BatchPolicy, *,
 
     def admit() -> None:
         nonlocal i
-        while i < n and t_arr[i] <= now:
-            q.append(i)
-            i += 1
+        i = int(np.searchsorted(t_arr, now, side="right"))
 
     def resolve_pending() -> None:
         nonlocal pending, now
         # the one handle outstanding is the last wave dispatched
         with obs.span("sched.resolve", cat="sched", wave=n_waves - 1):
-            members, handle = pending
+            h0, h1, handle = pending
             w = timed(handle.result, 0.0)
             now += w
             walls["resolve_s"] += w
-            for r in members:
-                done[r] = now
+            done[h0:h1] = now
             pending = None
 
-    def try_fire() -> Optional[Tuple[List[int], str]]:
-        if not q:
+    def try_fire() -> Optional[Tuple[int, str]]:
+        queued = i - head
+        if not queued:
             return None
-        if len(q) >= policy.max_batch:
+        if queued >= policy.max_batch:
             kind = "full"
         elif (policy.mode == "continuous"
-              and now - t_arr[q[0]] >= policy.max_wait_s - 1e-12):
+              and now - t_arr[head] >= policy.max_wait_s - 1e-12):
             kind = "deadline"
         elif i >= n and pending is None:
             kind = "final"                       # end-of-stream drain
         else:
             return None
-        take = min(len(q), policy.max_batch)
-        return [q.popleft() for _ in range(take)], kind
+        return min(queued, policy.max_batch), kind
 
     def next_fire_time() -> Optional[float]:
         """Earliest virtual time a wave can fire, absent service."""
         cands = []
         if policy.mode == "continuous":
-            if q:
-                cands.append(t_arr[q[0]] + policy.max_wait_s)
+            if i > head:
+                cands.append(t_arr[head] + policy.max_wait_s)
             elif i < n:
                 cands.append(t_arr[i] + policy.max_wait_s)
-        need = policy.max_batch - len(q)
+        need = policy.max_batch - (i - head)
         if i + need - 1 < n:
             cands.append(t_arr[i + need - 1])    # the wave-filling arrival
         elif i < n:
             cands.append(t_arr[n - 1])           # last arrival → final drain
         return min(cands) if cands else None
 
-    def dispatch(padded: List[int], holder: dict) -> None:
+    def dispatch(padded: np.ndarray, holder: dict) -> None:
         with obs.span("sched.keys", cat="sched", wave=n_waves):
-            keys = [key_of(k) for k in padded]
+            keys = key_str[padded].tolist()
         holder["h"] = backend.read_batch_async(keys, replica=replica)
 
     with obs.span("sched.replay", cat="sched", n_requests=n):
@@ -233,10 +264,14 @@ def replay(backend, trace: RequestTrace, policy: BatchPolicy, *,
             with obs.span("sched.form", cat="sched", wave=n_waves):
                 fired = try_fire()
                 if fired is not None:
-                    members, kind = fired
+                    take, kind = fired
                     fires[kind] += 1
-                    ks = [int(kids[r]) for r in members]
-                    padded = pad_to_bucket(ks, policy)
+                    h0, h1 = head, head + take
+                    head = h1
+                    padded = kids[h0:h1]
+                    m = _bucket(take, policy)
+                    if m != take:
+                        padded = np.resize(padded, m)  # cycles the wave
             if fired is None:
                 if pending is not None:
                     resolve_pending()            # drain the in-flight wave
@@ -254,7 +289,7 @@ def replay(backend, trace: RequestTrace, policy: BatchPolicy, *,
                           % trace.n_keys for j in range(republish_n)]
                     w = timed(
                         lambda: (backend.write_batch(
-                            [(key_of(k), f"v@{n_waves}") for k in sl],
+                            [(name(k), f"v@{n_waves}") for k in sl],
                             replica=writer), backend.fence()),
                         service_model(len(sl)) if service_model else 0.0)
                     now += w
@@ -272,12 +307,12 @@ def replay(backend, trace: RequestTrace, policy: BatchPolicy, *,
                     service_model(len(padded)) if service_model else 0.0)
                 now += w
                 walls["dispatch_s"] += w
-                events.append(("read", list(padded)))
-                batch_sizes.append(len(ks))
+                events.append(("read", padded.tolist()))
+                batch_sizes.append(take)
                 padded_sizes.append(len(padded))
-                pending = (members, holder["h"])
+                pending = (h0, h1, holder["h"])
                 n_waves += 1
-                served += len(members)
+                served += take
         if pending is not None:
             resolve_pending()
 

@@ -1,6 +1,10 @@
 """Continuous-batcher tests: firing semantics (deterministic via
 ``service_model``), pow2 bucketing, the continuous-beats-fixed goodput
-property, ``form_waves``, and open-loop replay against a real fabric."""
+property, ``form_waves``, open-loop replay against a real fabric, and
+the index-range ``replay`` against the per-request oracle it replaced."""
+import collections
+import time
+
 import numpy as np
 import pytest
 
@@ -322,3 +326,248 @@ def test_replay_result_identical_traced_and_untraced():
     for field in ("t_end", "batch_sizes", "padded_sizes", "fires", "walls",
                   "events"):
         assert getattr(plain, field) == getattr(traced, field), field
+
+
+# ---------------------------------------------- per-request oracle replay
+def oracle_replay(backend, trace, policy, *, replica=1, writer=0,
+                  key_of=None, republish_every=0, republish_n=16,
+                  service_model=None):
+    """The per-request ``replay`` that the index-range one replaced, kept
+    as the oracle: a deque of request indices, members popped one by one,
+    a key string formatted per read, a completion stamp per member.
+    Spans left out; they move no result."""
+    key_of = key_of or (lambda k: f"prefix/{k}")
+    t_arr, kids, n = trace.t, trace.kid, len(trace)
+    q = collections.deque()
+    i = 0
+    now = 0.0
+    done = np.full(n, np.nan)
+    pending = None
+    events, batch_sizes, padded_sizes = [], [], []
+    fires = {"full": 0, "deadline": 0, "final": 0}
+    walls = {"dispatch_s": 0.0, "resolve_s": 0.0, "republish_s": 0.0}
+    n_waves = served = next_storm_at = n_storms = 0
+
+    def timed(fn, modeled):
+        t0 = time.perf_counter()
+        fn()
+        w = time.perf_counter() - t0
+        return w if service_model is None else modeled
+
+    def admit():
+        nonlocal i
+        while i < n and t_arr[i] <= now:
+            q.append(i)
+            i += 1
+
+    def resolve_pending():
+        nonlocal pending, now
+        members, handle = pending
+        w = timed(handle.result, 0.0)
+        now += w
+        walls["resolve_s"] += w
+        for r in members:
+            done[r] = now
+        pending = None
+
+    def try_fire():
+        if not q:
+            return None
+        if len(q) >= policy.max_batch:
+            kind = "full"
+        elif (policy.mode == "continuous"
+              and now - t_arr[q[0]] >= policy.max_wait_s - 1e-12):
+            kind = "deadline"
+        elif i >= n and pending is None:
+            kind = "final"
+        else:
+            return None
+        take = min(len(q), policy.max_batch)
+        return [q.popleft() for _ in range(take)], kind
+
+    def next_fire_time():
+        cands = []
+        if policy.mode == "continuous":
+            if q:
+                cands.append(t_arr[q[0]] + policy.max_wait_s)
+            elif i < n:
+                cands.append(t_arr[i] + policy.max_wait_s)
+        need = policy.max_batch - len(q)
+        if i + need - 1 < n:
+            cands.append(t_arr[i + need - 1])
+        elif i < n:
+            cands.append(t_arr[n - 1])
+        return min(cands) if cands else None
+
+    def dispatch(padded, holder):
+        keys = [key_of(k) for k in padded]
+        holder["h"] = backend.read_batch_async(keys, replica=replica)
+
+    while True:
+        admit()
+        fired = try_fire()
+        if fired is not None:
+            members, kind = fired
+            fires[kind] += 1
+            ks = [int(kids[r]) for r in members]
+            padded = pad_to_bucket(ks, policy)
+        if fired is None:
+            if pending is not None:
+                resolve_pending()
+                continue
+            nft = next_fire_time()
+            if nft is None:
+                break
+            now = max(now, nft)
+            continue
+        if republish_every and served >= next_storm_at:
+            if pending is not None:
+                resolve_pending()
+            sl = [(n_storms * republish_n + j) % trace.n_keys
+                  for j in range(republish_n)]
+            w = timed(
+                lambda: (backend.write_batch(
+                    [(key_of(k), f"v@{n_waves}") for k in sl],
+                    replica=writer), backend.fence()),
+                service_model(len(sl)) if service_model else 0.0)
+            now += w
+            walls["republish_s"] += w
+            events.append(("write", sl))
+            events.append(("fence",))
+            n_storms += 1
+            next_storm_at += republish_every
+        if pending is not None:
+            resolve_pending()
+        holder = {}
+        w = timed(lambda: dispatch(padded, holder),
+                  service_model(len(padded)) if service_model else 0.0)
+        now += w
+        walls["dispatch_s"] += w
+        events.append(("read", list(padded)))
+        batch_sizes.append(len(ks))
+        padded_sizes.append(len(padded))
+        pending = (members, holder["h"])
+        n_waves += 1
+        served += len(members)
+    if pending is not None:
+        resolve_pending()
+    return scheduler.ReplayResult(
+        latency_s=done - t_arr, t_end=now, batch_sizes=batch_sizes,
+        padded_sizes=padded_sizes, fires=fires, walls=walls, events=events)
+
+
+class CallLog:
+    """Records every backend call with all its arguments, the way the
+    serving benchmark's proxy does; read results are the key strings."""
+
+    def __init__(self):
+        self.calls = []
+
+    def read_batch_async(self, keys, replica=1):
+        self.calls.append(("read", keys, replica))
+        return FakeHandle(keys)
+
+    def write_batch(self, items, replica=0):
+        self.calls.append(("write", list(items), replica))
+
+    def fence(self):
+        self.calls.append(("fence",))
+
+
+def _arrivals(kind, n, seed):
+    if kind == "backlog":
+        return mk_trace(np.zeros(n),
+                        np.random.default_rng(seed).integers(0, 40, n),
+                        n_keys=48)
+    return synthesize(n, 48, process=kind, rate=4000.0, seed=seed)
+
+
+SVC_BY_SIZE = lambda b: 2e-4 + 1e-5 * b     # deterministic, size-dependent
+
+
+@pytest.mark.parametrize("storm_every", [0, 37])
+@pytest.mark.parametrize("max_batch,min_bucket,bucket", [
+    (16, 8, True),          # power-of-two waves
+    (12, 8, True),          # full waves pad 12 -> 16
+    (6, 32, True),          # min_bucket above every wave
+    (10, 8, False),         # no padding at all
+])
+@pytest.mark.parametrize("arrivals", ["backlog", "poisson", "burst"])
+@pytest.mark.parametrize("mode", ["continuous", "fixed"])
+def test_replay_equals_per_request_oracle(mode, arrivals, max_batch,
+                                          min_bucket, bucket, storm_every):
+    tr = _arrivals(arrivals, 301, seed=11)
+    pol = BatchPolicy(mode=mode, max_batch=max_batch, max_wait_s=2e-3,
+                      min_bucket=min_bucket, bucket=bucket)
+    kw = dict(replica=3, writer=2, republish_every=storm_every,
+              republish_n=5, service_model=SVC_BY_SIZE)
+    want_log, got_log = CallLog(), CallLog()
+    want = oracle_replay(want_log, tr, pol, **kw)
+    got = replay(got_log, tr, pol, **kw)
+    assert np.array_equal(got.latency_s, want.latency_s)
+    for field in ("t_end", "batch_sizes", "padded_sizes", "fires", "walls",
+                  "events"):
+        assert getattr(got, field) == getattr(want, field), field
+    assert got_log.calls == want_log.calls
+    assert all(type(k) is int for e in got.events if e[0] != "fence"
+               for k in e[1])
+    assert all(type(c[1]) is list and all(type(k) is str for k in c[1])
+               for c in got_log.calls if c[0] == "read")
+
+
+def test_oracle_cases_reach_every_fire_kind():
+    """The equivalence cases above are not all full waves: deadline and
+    final fires, partial pads and storms all occur among them."""
+    kinds = collections.Counter()
+    for mode in ("continuous", "fixed"):
+        for arrivals in ("backlog", "poisson", "burst"):
+            res = replay(CallLog(), _arrivals(arrivals, 301, seed=11),
+                         BatchPolicy(mode=mode, max_batch=12,
+                                     max_wait_s=2e-3),
+                         service_model=SVC_BY_SIZE)
+            kinds.update({k: v for k, v in res.fires.items() if v})
+            kinds["partial"] += sum(b < 12 for b in res.batch_sizes)
+    assert set(kinds) == {"full", "deadline", "final", "partial"}
+
+
+class UncheckedTrace:
+    """What ``replay`` reads of a trace, without ``RequestTrace``'s own
+    validation (the serving benchmark brings a trace class of its own)."""
+
+    def __init__(self, t, kid, n_keys):
+        self.t, self.kid, self.n_keys = t, kid, n_keys
+
+    def __len__(self):
+        return len(self.t)
+
+
+def test_replay_refuses_decreasing_arrivals():
+    tr = UncheckedTrace(np.array([0.0, 2.0, 1.0]), np.zeros(3, np.int32), 1)
+    with pytest.raises(ValueError):
+        replay(FakeBackend(), tr, BatchPolicy(), service_model=SVC)
+
+
+@pytest.mark.parametrize("storm_every", [0, 50])
+def test_key_of_runs_once_per_distinct_key(storm_every):
+    kid = np.random.default_rng(5).choice([3, 9, 17, 40, 41], size=500)
+    tr = mk_trace(np.zeros(500), kid, n_keys=64)
+    seen = collections.Counter()
+
+    def key_of(k):
+        assert type(k) is int
+        seen[k] += 1
+        return f"user{k}"
+
+    log = CallLog()
+    res = replay(log, tr, BatchPolicy(max_batch=64, min_bucket=8),
+                 key_of=key_of, republish_every=storm_every, republish_n=4,
+                 service_model=SVC)
+    storm_ids = {k for e in res.events if e[0] == "write" for k in e[1]}
+    assert set(seen) == set(kid.tolist()) | storm_ids
+    assert set(seen.values()) == {1}
+    reads = [c[1] for c in log.calls if c[0] == "read"]
+    assert len(reads) == 8 and all(type(r) is list for r in reads)
+    assert [k for r in reads for k in r][:500] == \
+        [f"user{k}" for k in kid.tolist()]
+    # every wave hands over the same string object for the same key
+    assert len({id(k) for r in reads for k in r}) == len(set(kid.tolist()))
